@@ -33,6 +33,7 @@ import jax  # noqa: E402
 
 from repro.core import bruteforce, diversify  # noqa: E402
 from repro.core.engine import Searcher, SearchSpec  # noqa: E402
+from repro.launch.runtime import enable_compile_cache  # noqa: E402
 from repro.launch.server import AnnServer, ServeConfig  # noqa: E402
 
 # Offered load as a fraction of measured closed-batch (serial) capacity.
@@ -276,19 +277,11 @@ def _build_world(n: int, d: int, pool_q: int, key):
     return searcher, np.asarray(pool, np.float32), gt
 
 
-def _beam_cache_size():
-    """Compiled-executable count of the beam core, or None when the running
-    jax doesn't expose it (the 0.5.x matrix leg) — the no-recompile-after-
-    flip assertion degrades to advisory there instead of failing the smoke."""
+def _beam_cache_size() -> int:
+    """Compiled-executable count of the beam core."""
     from repro.core import beam_search as bs
 
-    fn = bs.beam_search
-    if hasattr(fn, "_cache_size"):
-        try:
-            return int(fn._cache_size())
-        except Exception:
-            return None
-    return None
+    return int(bs.beam_search._cache_size())
 
 
 def mutation_cycle(args) -> None:
@@ -398,7 +391,7 @@ def mutation_cycle(args) -> None:
         failures.append(f"phase-B parity {ok_b}/{checked_b} (want {half})")
     if dead_hits:
         failures.append(f"{dead_hits} tombstoned ids served as answers")
-    if cache_at_flip is not None and cache_after != cache_at_flip:
+    if cache_after != cache_at_flip:
         failures.append(f"beam core compiled post-flip "
                         f"({cache_at_flip} -> {cache_after} executables)")
     if not compact_ok:
@@ -431,6 +424,7 @@ def main() -> None:
                          "capacity)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mode == "mutation":
         mutation_cycle(args)

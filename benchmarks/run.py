@@ -14,6 +14,7 @@ import jax
 sys.path.insert(0, "src")
 
 from repro.data.synthetic import make_ann_dataset  # noqa: E402
+from repro.launch.runtime import enable_compile_cache  # noqa: E402
 
 from . import (  # noqa: E402
     fig3_categories,
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list of benches: tab1,fig3,fig4,fig5,fig6,smoke")
     args = ap.parse_args()
+    enable_compile_cache()
     scale_small = {"RAND10M4D": 2e-3, "RAND10M8D": 2e-3, "RAND10M16D": 2e-3,
                    "RAND10M32D": 2e-3, "RAND1M": 2e-2, "SIFT1M": 2e-2,
                    "GIST1M": 1e-2, "GLOVE1M": 2e-2}

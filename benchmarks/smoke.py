@@ -22,6 +22,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import bruteforce, diversify  # noqa: E402
 from repro.core.engine import ENTRY_STRATEGIES, Searcher, SearchSpec  # noqa: E402
+from repro.launch.runtime import enable_compile_cache  # noqa: E402
 
 try:
     from .bench_util import timeit  # noqa: E402
@@ -685,6 +686,7 @@ def main() -> None:
                          "(default: the main world's --n; nightly CI passes "
                          "6000,60000,200000)")
     args = ap.parse_args()
+    enable_compile_cache()
     tier_ns = ([int(v) for v in args.host_tier_ns.split(",") if v]
                if args.host_tier_ns else None)
     run(n=args.n, d=args.d, q=args.q, ef=args.ef,

@@ -635,6 +635,7 @@ def projection_entries(
     return ids.astype(jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnames=("n", "Q", "E"))
 def random_entries(key: jax.Array, n: int, Q: int, E: int) -> jax.Array:
     """E random seeds per query (flat-HNSW start, paper Sec. IV).
 

@@ -29,6 +29,7 @@ functions and never touch the engine or its callers (DESIGN.md §10).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, NamedTuple
 
@@ -419,20 +420,26 @@ def graph_recall_proxy(base, graph: KnnGraph, metric: str = "l2",
     adjacency, measured on ``sample`` evenly spaced vertices (deterministic,
     no key). The KGraph quality metric without the O(n^2) exact graph —
     cheap enough to run on every build and gate in CI."""
-    from .bruteforce import exact_search
-
     n = graph.n
     k = min(k, graph.degree, n - 1)
     s = min(sample, n)
+    return float(_proxy_hits(base, graph.neighbors, metric, k, s))
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "k", "s"))
+def _proxy_hits(base, neighbors, metric: str, k: int, s: int):
+    from .bruteforce import exact_search
+
+    n = neighbors.shape[0]
     rows = jnp.arange(s, dtype=jnp.int32) * (n // s)
     # k+1 then drop self by id (robust for non-l2 metrics)
     _, ids = exact_search(base[rows], base, k + 1, metric)
     notself = ids != rows[:, None]
     order = jnp.argsort(~notself, axis=1, stable=True)
     exact_ids = jnp.take_along_axis(ids, order, axis=1)[:, :k]
-    nbrs = graph.neighbors[rows]
+    nbrs = neighbors[rows]
     hit = (exact_ids[:, :, None] == nbrs[:, None, :]).any(-1)
-    return float(hit.mean())
+    return hit.mean()
 
 
 # -- the builder --------------------------------------------------------------

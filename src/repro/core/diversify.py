@@ -60,6 +60,19 @@ def add_reverse_edges_with_stats(
     edge — ``BuildReport`` surfaces them next to the realized degree
     distribution so a too-tight cap is visible, not silent.
     """
+    compact, n_valid, n_kept, n_cap = _reverse_union(neighbors, max_degree)
+    stats = ReverseUnionStats(
+        candidates=int(n_valid),
+        dropped_slot=int(n_valid) - int(n_kept),
+        dropped_cap=int(n_cap),
+    )
+    return compact, stats
+
+
+@functools.partial(jax.jit, static_argnames=("max_degree",))
+def _reverse_union(neighbors, max_degree: int):
+    """(capped union, valid forward edges, kept reverse slots, cap
+    evictions) — the device half of :func:`add_reverse_edges_with_stats`."""
     n, r = neighbors.shape
     src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, r)).ravel()
     tgt = neighbors.ravel()
@@ -93,12 +106,8 @@ def add_reverse_edges_with_stats(
     key = jnp.where(ids_sorted == INVALID, jnp.iinfo(jnp.int32).max, 0)
     order2 = jnp.argsort(key, axis=1, stable=True)
     compact = jnp.take_along_axis(ids_sorted, order2, axis=1)
-    stats = ReverseUnionStats(
-        candidates=int(valid.sum()),
-        dropped_slot=int(valid.sum()) - int(keep.sum()),
-        dropped_cap=int((compact[:, max_degree:] != INVALID).sum()),
-    )
-    return compact[:, :max_degree], stats
+    return (compact[:, :max_degree], valid.sum(), keep.sum(),
+            (compact[:, max_degree:] != INVALID).sum())
 
 
 def add_reverse_edges(neighbors: jax.Array, max_degree: int) -> jax.Array:

@@ -674,9 +674,15 @@ class Searcher:
 
     def _remap_entries(self, entries, cf: CompiledFilter | None,
                        key: jax.Array | None):
-        """Seed redraw for filtered graph search: denied seeds become
-        uniform draws from the allowed set (row-index-keyed, so served
-        bucket-padded rows redraw bit-identically to direct search)."""
+        """Seed redraw: seeds a filter denies, or that the tombstones mark
+        dead, become uniform draws from the allowed (live) set — row-index-
+        keyed, so served bucket-padded rows redraw bit-identically to direct
+        search. Without it a row whose every seed is dead (likely when most
+        of a mutable index's capacity is unallocated) answers nothing."""
+        if cf is None and self.tombstones is not None:
+            live = self.compiled_filter(FilterSpec())  # allowed = not dead
+            if live.n_allowed < self.neighbors.shape[0]:
+                cf = live
         if cf is None:
             return entries
         return remap_denied_seeds(

@@ -7,6 +7,8 @@ The dataset LID is the average of per-point estimates over a subsample
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -20,9 +22,14 @@ def lid_mle(
     metric: str = "l2",
     key: jax.Array | None = None,
 ) -> jax.Array:
-    n = x.shape[0]
     if key is None:
         key = jax.random.PRNGKey(0)
+    return _lid_mle(x, key, k, sample, metric)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "sample", "metric"))
+def _lid_mle(x, key, k: int, sample: int, metric: str):
+    n = x.shape[0]
     idx = jax.random.choice(key, n, shape=(min(sample, n),), replace=False)
     queries = x[idx]
     d, _ = exact_search(queries, x, k + 1, metric=metric)

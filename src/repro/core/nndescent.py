@@ -8,7 +8,8 @@ the CPU algorithm becomes fixed-shape rounds:
   3. add reverse-edge candidates via a random-slot scatter (collisions drop
      entries — NN-Descent is stochastic already; recall is validated in tests),
   4. score all candidates with the fused gather+distance kernel, chunked so
-     the (chunk, C, d) gather stays inside VMEM-scale working sets,
+     the (chunk, C, d) gather stays inside VMEM-scale working sets, and push
+     each vertex's K best scored edges back to their other ends,
   5. merge into the sorted K-list with fixed-shape dedup.
 
 The update counter gives the standard early-termination rule (delta * n * K).
@@ -16,6 +17,7 @@ The update counter gives the standard early-termination rule (delta * n * K).
 from __future__ import annotations
 
 import functools
+import time
 from typing import NamedTuple
 
 import jax
@@ -107,26 +109,30 @@ def _round(base, ids, dists, isnew, key, cfg: NNDescentConfig, metric: str):
     cand_d = _score_chunked(base, pool, metric, cfg.chunk)
 
     # -- 4b. symmetric push-back: the original local join updates BOTH ends of
-    # a compared pair. Scatter each scored edge (v -> c, d) into c's incoming
-    # buffer (random slot, collisions drop) and merge it too.
-    C = pool.shape[1]
+    # a compared pair. Scatter each vertex's k best scored edges (v -> c, d)
+    # into c's incoming buffer (random slot, collisions drop) and merge them
+    # too. Pushing the k best rather than all C candidates keeps the edges
+    # that can enter c's list and cuts the scatter (which XLA:TPU lowers to
+    # a sort of its indices) from n*C to n*k updates. One scatter of the
+    # edge's flat position carries both ends: (id, dist) pairs stay
+    # consistent whichever writer wins a slot.
+    _, best = jax.lax.top_k(-cand_d, k)
+    push_pool = jnp.take_along_axis(pool, best, axis=1)            # (n, k)
+    push_cand = jnp.take_along_axis(cand_d, best, axis=1)
+    C = push_pool.shape[1]
     kp = jax.random.fold_in(key, 7)
     rb = max(k, cfg.reverse)
     pslots = jax.random.randint(kp, (n, C), 0, rb)
-    pvalid = pool >= 0
-    flat_tgt = jnp.where(pvalid, pool, 0).ravel()
-    push_i = jnp.full((n, rb), INVALID, jnp.int32)
-    push_d = jnp.full((n, rb), jnp.inf, jnp.float32)
-    push_src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, C))
-    push_i = push_i.at[flat_tgt, pslots.ravel()].set(
-        jnp.where(pvalid, push_src, INVALID).ravel(), mode="drop"
-    )
-    push_d = push_d.at[flat_tgt, pslots.ravel()].set(
-        jnp.where(pvalid, cand_d, jnp.inf).ravel(), mode="drop"
-    )
-    # slot collisions may desync (id, dist) pairs only if two writers hit the
-    # same slot between the two scatters — scatters are elementwise-identical
-    # ordered in XLA, so the last writer wins both; pairs stay consistent.
+    pvalid = (push_pool >= 0) & jnp.isfinite(push_cand)
+    flat_tgt = jnp.where(pvalid, push_pool, 0).ravel()
+    pos = jnp.arange(n * C, dtype=jnp.int32)
+    push_pos = jnp.full((n, rb), INVALID, jnp.int32).at[
+        flat_tgt, pslots.ravel()
+    ].set(jnp.where(pvalid.ravel(), pos, INVALID), mode="drop")
+    pushed = push_pos >= 0
+    push_i = jnp.where(pushed, push_pos // C, INVALID)
+    push_d = jnp.where(pushed, push_cand.ravel()[jnp.maximum(push_pos, 0)],
+                       jnp.inf)
 
     # -- 5. merge ------------------------------------------------------------
     def merge(row_d, row_i, cd, ci, pd, pi):
@@ -146,6 +152,15 @@ def _round(base, ids, dists, isnew, key, cfg: NNDescentConfig, metric: str):
 @functools.partial(jax.jit, static_argnames=("cfg", "metric"), donate_argnums=(1, 2, 3))
 def _round_jit(base, ids, dists, isnew, key, cfg, metric):
     return _round(base, ids, dists, isnew, key, cfg, metric)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "metric"))
+def _init_jit(base, key, cfg, metric):
+    """Random k-lists, scored and deduped: the state round 0 starts from."""
+    ids = _random_init(key, base.shape[0], cfg.k)
+    dists = _score_chunked(base, ids, metric, cfg.chunk)
+    dists, ids = jax.vmap(dedup_by_id)(dists, ids)
+    return ids, dists, jnp.ones_like(ids, dtype=bool)
 
 
 class NNDescentStats(NamedTuple):
@@ -180,21 +195,20 @@ def build_knn_graph_with_stats(
         key = jax.random.PRNGKey(0)
     n = base.shape[0]
     k0, key = jax.random.split(key)
-    ids = _random_init(k0, n, cfg.k)
-    dists = _score_chunked(base, ids, metric, cfg.chunk)
-    dists, ids = jax.vmap(dedup_by_id)(dists, ids)
-    isnew = jnp.ones_like(ids, dtype=bool)
+    ids, dists, isnew = _init_jit(base, k0, cfg, metric)
 
     threshold = cfg.delta * n * cfg.k
     curve: list[int] = []
     converged = False
     for r in range(cfg.rounds):
+        t0 = time.perf_counter()
         key, kr = jax.random.split(key)
         ids, dists, isnew, n_up = _round_jit(base, ids, dists, isnew, kr, cfg, metric)
         n_up = int(n_up)
         curve.append(n_up)
         if verbose:
-            print(f"[nndescent] round {r}: {n_up} updates")
+            print(f"[nndescent] round {r}: {n_up} updates, "
+                  f"{time.perf_counter() - t0:.3f} s", flush=True)
         if n_up <= threshold:
             converged = True
             break

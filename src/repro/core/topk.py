@@ -30,16 +30,14 @@ def sort_by_distance(dists: jax.Array, ids: jax.Array) -> tuple[jax.Array, jax.A
 def dedup_by_id(dists: jax.Array, ids: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Mask duplicate ids (keep smallest distance per id). Fixed shape.
 
-    Works on 1-D lists; vmap for batches. Strategy: sort by (id, dist), mark
-    entries equal to their predecessor id, set their distance to +inf.
+    Works on the last axis (vmap or batch freely). Strategy: one stable
+    lexicographic sort by (id, dist), mark entries equal to their
+    predecessor id, set their distance to +inf, then sort by distance —
+    ties in distance fall back to id order.
     """
-    # Sort primarily by id, secondarily by distance: encode as lexsort via two
-    # stable argsorts (distance first, then id).
-    order_d = jnp.argsort(dists, stable=True)
-    ids_d, dists_d = ids[order_d], dists[order_d]
-    order_i = jnp.argsort(ids_d, stable=True)
-    ids_s, dists_s = ids_d[order_i], dists_d[order_i]
-    dup = jnp.concatenate([jnp.array([False]), ids_s[1:] == ids_s[:-1]])
+    ids_s, dists_s = jax.lax.sort((ids, dists), num_keys=2)
+    first = jnp.zeros(ids.shape[:-1] + (1,), bool)
+    dup = jnp.concatenate([first, ids_s[..., 1:] == ids_s[..., :-1]], axis=-1)
     dup = dup | (ids_s == INVALID)
     dists_s = jnp.where(dup, INF, dists_s)
     ids_s = jnp.where(dup, INVALID, ids_s)

@@ -13,6 +13,7 @@ criteo-like batches for recsys, SBM graphs for the GNN — all deterministic in
 from __future__ import annotations
 
 import functools
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +58,12 @@ def make_ann_dataset(
     name: str, key: jax.Array | None = None, scale: float = 1.0, n_queries: int = 1000
 ):
     """Returns (base (n, d), queries (q, d), metric). ``scale`` shrinks n for
-    CI (benchmarks use --full for paper sizes)."""
+    CI (benchmarks use --full for paper sizes). The default key derives from
+    the name by CRC-32, so every process draws the same corpus (``hash`` is
+    salted per process)."""
     spec = PAPER_DATASETS[name]
     if key is None:
-        key = jax.random.PRNGKey(hash(name) % (2**31))
+        key = jax.random.PRNGKey(zlib.crc32(name.encode()) & 0x7FFFFFFF)
     n = max(int(spec["n"] * scale), 1000)
     kb, kq = jax.random.split(key)
     if spec["latent"] is None:

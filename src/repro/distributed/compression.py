@@ -92,7 +92,7 @@ def compressed_psum_topk(
 def make_compressed_allreduce(mesh, scheme: str = "int8", k_frac: float = 0.01):
     """Returns fn(grads, key) -> averaged grads, expressed via shard_map over
     the mesh's data axes so the wire format is explicit in the HLO."""
-    from repro.distributed._compat import shard_map
+    from jax import shard_map
 
     data_axes = tuple(a for a in mesh.axis_names if a != "model")
 

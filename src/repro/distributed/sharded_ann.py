@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed._compat import shard_map
+from jax import shard_map
 
 from repro.core import engine
 from repro.core.base_store import BaseStore, check_placement, rerank_gathered

@@ -6,15 +6,17 @@ fetching (R_tile, d) float rows and contracting against the query, fetch
 score them against the query's VMEM-resident (M, K) ADC lookup table —
 M bytes of traffic per scored vertex instead of 4d.
 
-Layout mirrors the exact kernel: grid = (Q, R/R_tile), the code table stays
-in HBM (``pl.ANY``), each grid step issues R_tile row DMAs into a
-double-buffered (2, R_tile, M) VMEM scratch, and the per-query LUT's
-BlockSpec revisits the same (1, M, K) block across the inner tile loop. TPU
-has no fast per-lane gather, so the LUT lookup is recast as one-hot matmuls
-(as in ``pq_adc``): each code column m becomes onehot(codes[:, m]) @ lut[m],
-an (R_tile, K) x (K,) MXU contraction, K x M MACs per row vs d for exact.
+Layout mirrors the exact kernel (``gather_distance.gather_scores``): grid =
+(Q, R/R_tile), the code table stays in HBM (``pl.ANY``), each grid step
+issues R_tile row-block DMAs (aligned 8-row blocks of the lane-padded table:
+uint8 rows cannot be fetched alone) into a double-buffered VMEM scratch,
+and the per-query LUT's BlockSpec revisits the same (1, M, K) block across
+the inner tile loop. TPU has no fast per-lane gather, so the LUT lookup is
+recast as one-hot matmuls (as in ``pq_adc``): each code column m becomes
+lut[m] . onehot(codes[:, m]), a (1, K) x (R_tile, K) MXU contraction, K x M
+MACs per row vs d for exact.
 
-The epilogue is identical to the exact kernel's: padding ids (< 0) and
+The masking contract is the exact kernel's: padding ids (< 0) and
 bitmap-visited ids come back as (+inf, INVALID), so ``beam_search._step``
 consumes (dists, masked ids) directly regardless of the scorer.
 """
@@ -24,54 +26,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .gather_distance import (
-    DEFAULT_R_TILE,
-    _pad_ids,
-    fetch_rows_double_buffered,
-    mask_epilogue,
-)
+from .gather_distance import DEFAULT_R_TILE, gather_scores
+from .ref import visited_mask_ref
 
 
-def _adc_tile_scores(tile, lut) -> jax.Array:
-    """(R_tile, M) int32 codes x (M, K) f32 LUT -> (1, R_tile) ADC scores."""
+def _adc_tile(codes, lut) -> jax.Array:
+    """(R_tile, M) uint8 codes x (M, K) f32 LUT -> (1, R_tile) ADC scores."""
     M, K = lut.shape
-    acc = jnp.zeros((tile.shape[0],), jnp.float32)
+    codes = codes.astype(jnp.int32)
+    lut = lut.astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], K), 1)
+    acc = jnp.zeros((1, codes.shape[0]), jnp.float32)
     for m in range(M):  # static unroll; M is 8/16
-        onehot = (tile[:, m][:, None] == jnp.arange(K)[None, :]).astype(
-            jnp.float32
-        )
+        onehot = (codes[:, m:m + 1] == col).astype(jnp.float32)
+        # HIGHEST: a 0/1 x fp32 contraction is exact only without bf16
+        # truncation of the LUT entries
         acc = acc + jax.lax.dot_general(
-            onehot, lut[m], (((1,), (0,)), ((), ())),
+            lut[m:m + 1, :], onehot, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
-    return acc[None, :]
-
-
-def _ga_tiled_kernel(
-    # scalar prefetch
-    ids_sref,
-    # inputs
-    idv_ref,
-    lut_ref,
-    vis_ref,
-    codes_ref,
-    # outputs
-    d_ref,
-    oid_ref,
-    # scratch
-    rows,
-    sems,
-    *,
-    r_tile: int,
-):
-    slot = fetch_rows_double_buffered(ids_sref, codes_ref, rows, sems, r_tile)
-    lut = lut_ref[0].astype(jnp.float32)                   # (M, K)
-    tile = rows[pl.ds(slot, 1)][0].astype(jnp.int32)       # (R_tile, M)
-    d = _adc_tile_scores(tile, lut)                        # (1, R_tile)
-    mask_epilogue(idv_ref[...], d, d_ref, oid_ref, vis_ref)
+    return acc
 
 
 @functools.partial(jax.jit, static_argnames=("r_tile", "interpret"))
@@ -91,38 +67,7 @@ def gather_adc_masked(
     (+inf, INVALID). Metric-agnostic — the LUT carries the metric
     (``baselines.pq.build_adc_luts``).
     """
-    Q, R = ids.shape
-    M = codes.shape[1]
-    K = luts.shape[2]
-    rt = max(1, min(r_tile, R))
-    ids_p, Rp = _pad_ids(ids, rt)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Q, Rp // rt),
-        in_specs=[
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),   # ids tile
-            pl.BlockSpec((1, M, K), lambda q, t, ids: (q, 0, 0)),  # query LUT
-            pl.BlockSpec(
-                (1, visited.shape[1]), lambda q, t, ids: (q, 0)
-            ),                                                 # visited row
-            pl.BlockSpec(memory_space=pltpu.ANY),              # codes, HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, rt, M), codes.dtype),
-            pltpu.SemaphoreType.DMA((2, rt)),
-        ],
-    )
-    dists, oids = pl.pallas_call(
-        functools.partial(_ga_tiled_kernel, r_tile=rt),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, Rp), jnp.float32),
-            jax.ShapeDtypeStruct((Q, Rp), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ids_p, ids_p, luts, visited, codes)
-    return dists[:, :R], oids[:, :R]
+    masked = visited_mask_ref(ids, visited)
+    dists = gather_scores(_adc_tile, masked, codes, [luts], r_tile=r_tile,
+                          interpret=interpret)
+    return dists, masked

@@ -7,14 +7,15 @@ traffic instead of 4d (exact) while keeping full-rank geometry — unlike PQ
 there is no subspace factorization, so recall sits between exact and pq at
 every d (the property ``pq_sweep`` tracks).
 
-Layout is the exact kernel's (``gather_distance``): grid = (Q, R/R_tile),
-the uint8 table stays in HBM (``pl.ANY``), each grid step issues R_tile row
-DMAs into a double-buffered (2, R_tile, d) VMEM scratch, dequantizes the
-tile on the VPU (one fused multiply-add against the VMEM-resident (1, d)
-scale/min rows), and reduces against the query with the same MXU
-contraction + metric epilogue as the float kernel.
+Layout is the exact kernel's (``gather_distance.gather_scores``): grid =
+(Q, R/R_tile), the uint8 table stays in HBM (``pl.ANY``), each grid step
+issues R_tile row-block DMAs (aligned 8-row blocks: uint8 rows cannot be
+fetched alone) into a double-buffered VMEM scratch,
+dequantizes the tile on the VPU (one fused multiply-add against the
+VMEM-resident (1, d) scale/min rows), and reduces against the query with the
+same MXU contraction + metric epilogue as the float kernel.
 
-The mask epilogue is shared verbatim: padding ids (< 0) and bitmap-visited
+The masking contract is shared too: padding ids (< 0) and bitmap-visited
 ids come back as (+inf, INVALID), so ``beam_search._step`` consumes
 (dists, masked ids) directly regardless of the scorer.
 """
@@ -24,44 +25,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .gather_distance import (
-    DEFAULT_R_TILE,
-    _pad_ids,
-    _tile_distances,
-    fetch_rows_double_buffered,
-    mask_epilogue,
-)
+from .gather_distance import (DEFAULT_R_TILE, _tile_distances, gather_scores,
+                              lane_pad)
+from .ref import visited_mask_ref
 
 
-def _gs_tiled_kernel(
-    # scalar prefetch
-    ids_sref,
-    # inputs
-    idv_ref,
-    q_ref,
-    sc_ref,
-    mn_ref,
-    vis_ref,
-    codes_ref,
-    # outputs
-    d_ref,
-    oid_ref,
-    # scratch
-    rows,
-    sems,
-    *,
-    metric: str,
-    r_tile: int,
-):
-    slot = fetch_rows_double_buffered(ids_sref, codes_ref, rows, sems, r_tile)
-    q = q_ref[...].astype(jnp.float32)                     # (1, d)
-    tile = rows[pl.ds(slot, 1)][0].astype(jnp.float32)     # (R_tile, d)
-    tile = tile * sc_ref[...] + mn_ref[...]                # dequant, VPU FMA
-    d = _tile_distances(q, tile, metric)                   # (1, R_tile)
-    mask_epilogue(idv_ref[...], d, d_ref, oid_ref, vis_ref)
+def _sq8_tile(metric):
+    def score(codes, q, scale, mn):
+        rows = codes.astype(jnp.int32).astype(jnp.float32) * scale + mn
+        return _tile_distances(q.astype(jnp.float32), rows, metric)
+    return score
 
 
 @functools.partial(
@@ -86,40 +60,12 @@ def gather_sq8_masked(
     entries come back as (+inf, INVALID).
     """
     Q, d = queries.shape
-    R = ids.shape[1]
-    rt = max(1, min(r_tile, R))
-    ids_p, Rp = _pad_ids(ids, rt)
-    sc2 = jnp.asarray(scale, jnp.float32).reshape(1, d)
-    mn2 = jnp.asarray(mn, jnp.float32).reshape(1, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Q, Rp // rt),
-        in_specs=[
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),   # ids tile
-            pl.BlockSpec((1, d), lambda q, t, ids: (q, 0)),    # query row
-            pl.BlockSpec((1, d), lambda q, t, ids: (0, 0)),    # dequant scale
-            pl.BlockSpec((1, d), lambda q, t, ids: (0, 0)),    # dequant min
-            pl.BlockSpec(
-                (1, visited.shape[1]), lambda q, t, ids: (q, 0)
-            ),                                                 # visited row
-            pl.BlockSpec(memory_space=pltpu.ANY),              # codes, HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),
-            pl.BlockSpec((1, rt), lambda q, t, ids: (q, t)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, rt, d), codes.dtype),
-            pltpu.SemaphoreType.DMA((2, rt)),
-        ],
+    masked = visited_mask_ref(ids, visited)
+    dists = gather_scores(
+        _sq8_tile(metric), masked, codes,
+        [lane_pad(queries).reshape(Q, 1, -1)],
+        [lane_pad(jnp.asarray(scale, jnp.float32).reshape(1, d)),
+         lane_pad(jnp.asarray(mn, jnp.float32).reshape(1, d))],
+        r_tile=r_tile, interpret=interpret,
     )
-    dists, oids = pl.pallas_call(
-        functools.partial(_gs_tiled_kernel, metric=metric, r_tile=rt),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, Rp), jnp.float32),
-            jax.ShapeDtypeStruct((Q, Rp), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ids_p, ids_p, queries, sc2, mn2, visited, codes)
-    return dists[:, :R], oids[:, :R]
+    return dists, masked

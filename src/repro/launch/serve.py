@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import json
 import os
 import time
 
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.runtime import device_summary, enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_test_mesh
 
 
@@ -384,6 +386,7 @@ def main() -> None:
                          "pre-flip, zero drops), then serve a second "
                          "stream against it (DESIGN.md §13)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.serve and args.arch != "ann":
         raise SystemExit("--serve is an --arch ann mode")
@@ -392,6 +395,7 @@ def main() -> None:
 
     if args.arch == "ann":
         serve_ann(args)
+        print(json.dumps({"ok": True, "device": device_summary()}))
         return
 
     ad = configs.get_arch(args.arch)

@@ -55,6 +55,7 @@ module is the serving layer between the two:
 from __future__ import annotations
 
 import bisect
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -121,6 +122,20 @@ def _percentiles(ms: np.ndarray) -> dict:
         "p99_ms": round(float(np.percentile(ms, 99)), 3),
         "mean_ms": round(float(ms.mean()), 3),
     }
+
+
+@functools.partial(jax.jit, static_argnames=("bucket",))
+def _pad_to_bucket(queries, entries, entry_comps, bucket: int):
+    """Pad a request's (queries, entries, comps) up to ``bucket`` rows —
+    zero queries, INVALID entries, zero comps — plus the (bucket,) q_valid
+    mask of its real rows."""
+    pad = bucket - queries.shape[0]
+    return (
+        jnp.pad(queries, ((0, pad), (0, 0))),
+        jnp.pad(entries, ((0, pad), (0, 0)), constant_values=INVALID),
+        jnp.pad(entry_comps, (0, pad)),
+        jnp.arange(bucket) < queries.shape[0],
+    )
 
 
 class AnnServer:
@@ -255,17 +270,9 @@ class AnnServer:
         spec = self.spec if spec is None else spec
         if filter is not None:
             spec = spec._replace(filter=filter)
-        qn, d = rows.shape
         dev = jax.device_put(rows)  # async: overlaps the in-flight batch
         ent, ecomps = searcher.seed(dev, spec, key)
-        pad = bucket - qn
-        if pad:
-            dev = jnp.concatenate([dev, jnp.zeros((pad, d), dev.dtype)])
-            ent = jnp.concatenate(
-                [ent, jnp.full((pad, ent.shape[1]), INVALID, jnp.int32)]
-            )
-            ecomps = jnp.concatenate([ecomps, jnp.zeros((pad,), ecomps.dtype)])
-        valid = jnp.arange(bucket) < qn
+        dev, ent, ecomps, valid = _pad_to_bucket(dev, ent, ecomps, bucket)
         # the request key ALSO rides into the search: restart keys are
         # fold_in(key, row_index), so the real rows of a padded bucket draw
         # the exact restart seeds a direct search would (pad rows hold keys

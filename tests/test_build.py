@@ -29,7 +29,7 @@ def world():
 
 def test_flat_build_matches_pre_pipeline_composition(world):
     """Searcher.build (now on GraphBuilder) == the inline NN-Descent + GD
-    composition it replaced, bit-for-bit."""
+    composition it replaced, bit-for-bit. Both sides run the same kernels, so this holds on a chip as well."""
     base, _, _ = world
     key = jax.random.PRNGKey(3)
     g = nndescent.build_knn_graph(
@@ -299,7 +299,7 @@ def test_shard_build_rejects_hierarchy(world):
 
 def test_shard_build_is_deterministic(world):
     """Same (spec, key) -> bit-identical per-shard graphs (the rebuild
-    reproducibility sharded deployments rely on)."""
+    reproducibility sharded deployments rely on). Both sides run the same kernels, so this holds on a chip as well."""
     from repro.distributed.sharded_ann import shard_build
 
     base, _, _ = world

@@ -42,6 +42,11 @@ def world():
 
 
 def test_flat_search_matches_pre_refactor_golden(world):
+    """Flat-graph search reproduces the committed golden outputs.
+    On a chip this golden does not apply: it pins the jnp reference
+    dispatch (``JAX_PLATFORMS=cpu``), while the native kernels compute l2
+    in expanded-norm form, so distances move in the low bits there (and
+    ids on exact ties)."""
     base, queries, gd, idx, _ = world
     gold = np.load(GOLDEN)
     r = hnsw.flat_search(queries, base, gd, ef=32, k=4,
@@ -52,6 +57,11 @@ def test_flat_search_matches_pre_refactor_golden(world):
 
 
 def test_hnsw_search_matches_pre_refactor_golden(world):
+    """Hierarchy-seeded search reproduces the committed golden outputs.
+    On a chip this golden does not apply: it pins the jnp reference
+    dispatch (``JAX_PLATFORMS=cpu``), while the native kernels compute l2
+    in expanded-norm form, so distances move in the low bits there (and
+    ids on exact ties)."""
     base, queries, gd, idx, _ = world
     gold = np.load(GOLDEN)
     r = hnsw.hnsw_search(queries, base, idx, ef=32, k=4)
@@ -392,7 +402,11 @@ def test_sq8_index_is_lazy_and_cached(world):
 def test_pq_search_matches_golden(world):
     """Determinism lock: a freshly trained PQ engine (k-means re-seeding
     folds the iteration index) reproduces the committed pq_* outputs
-    bit-for-bit — regenerate via tests/data/make_golden.py ONLY on purpose."""
+    bit-for-bit — regenerate via tests/data/make_golden.py ONLY on purpose.
+    On a chip this golden does not apply: it pins the jnp reference
+    dispatch (``JAX_PLATFORMS=cpu``), while the native kernels compute l2
+    in expanded-norm form, so distances move in the low bits there (and
+    ids on exact ties)."""
     base, queries, gd, idx, _ = world
     gold = np.load(GOLDEN)
     searcher = Searcher.from_graph(base, gd, key=jax.random.PRNGKey(7))
@@ -409,7 +423,10 @@ def test_host_placement_matches_golden(world):
     """base_placement='host' reruns the golden pq world off a host-resident
     base and must land on the committed pq_* outputs bit-for-bit — the
     tiered rerank is the same survivors, same distance formula, same bill
-    (DESIGN.md §9)."""
+    (DESIGN.md §9). On a chip this golden does not apply: it pins the jnp reference
+    dispatch (``JAX_PLATFORMS=cpu``), while the native kernels compute l2
+    in expanded-norm form, so distances move in the low bits there (and
+    ids on exact ties)."""
     base, queries, gd, idx, _ = world
     gold = np.load(GOLDEN)
     searcher = Searcher.from_graph(base, gd, key=jax.random.PRNGKey(7))

@@ -12,7 +12,7 @@ The load-bearing contracts:
 * **Operands, not recompiles** — new filter values never trace a new
   beam executable, direct or served.
 * **Parity** — a served request carrying a FilterSpec is bit-identical
-  to direct filtered search on its rows.
+  to direct filtered search on its rows (on a chip too: same kernels).
 * **Composition** — tombstones ∨ filter; metadata rides artifacts (v3)
   and MutableIndex mutation untouched.
 """

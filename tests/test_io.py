@@ -3,7 +3,8 @@
 The acceptance contract: a saved-then-loaded artifact yields BIT-IDENTICAL
 search results (ids/dists/n_comps) to the in-memory build — for flat,
 GD/DPG-diversified, hierarchical, and PQ-compressed indexes, under both
-base placements."""
+base placements. Both sides run the same kernels, so this holds on a chip
+as well."""
 import json
 import os
 

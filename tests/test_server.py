@@ -6,6 +6,8 @@ real rows vs direct ``Searcher.search`` on those rows alone — across every
 entry strategy, scorer, and base placement. Everything the server does
 (bucketing, admission, overlap) rests on that; the rest of the file locks
 the serving mechanics around it (bucket pick, shedding, timestamps, stats).
+Served and direct searches run the same kernels, so the parity holds on a
+chip as well.
 """
 import jax
 import jax.numpy as jnp
