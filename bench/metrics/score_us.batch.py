@@ -1,0 +1,9 @@
+"""Beam loop (core/beam_search.py): device self time of the ops under the
+program's ``beam_score`` scope (the lane pad, the gather kernel and the row
+selection of the scorer call) per beam step of the traced requests, the
+base of ``beam_step_us.batch``, in microseconds."""
+from bench.scopes import scope_us_per_step
+
+
+def read(run):
+    return scope_us_per_step(run, __file__, "beam_score")

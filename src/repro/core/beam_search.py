@@ -14,6 +14,13 @@ are masked, not exited (SIMT-style divergence handling).
 ``search_with_trace`` runs a fixed-step scan recording (min distance reached,
 cumulative comparisons) — the instrumentation behind paper Fig. 6.
 
+Every phase runs under a ``jax.named_scope`` (``beam_init``, ``beam_select``,
+``beam_expand``, ``beam_score``, ``beam_visited``, ``beam_merge``,
+``beam_freeze``, ``beam_finalize``), which names it in the HLO ``op_name``
+metadata and so in a device trace; the scopes change no computation. The
+scorer's test of the visited bitmap carries the kernels' own
+``visited_test`` scope, inside ``beam_score`` (``beam_init`` for the seeds).
+
 Termination is per query (DESIGN.md §12). ``term="fixed"`` keeps the classic
 rule only: a row stops when its best unexpanded candidate cannot improve the
 ef list. ``term="stable"`` additionally freezes a row whose top-k has not
@@ -156,6 +163,7 @@ def _mark_visited(visited: jax.Array, ids: jax.Array) -> jax.Array:
     return visited.at[q, word].add(bit, mode="drop")
 
 
+@jax.named_scope("beam_init")
 def _init_state(queries, base, neighbors, entry_ids, ef, metric,
                 r_tile: int = 0, scorer: str = "exact",
                 scorer_state=None, tombstones=None, deny=None) -> _State:
@@ -272,66 +280,72 @@ def _step(state: _State, queries, base, neighbors, metric,
     # beyond-paper variant: W vertices expand per step, trading a few extra
     # comparisons for W-fold fewer sequential steps (bigger fused gathers on
     # the MXU, W-fold fewer device round-trips) — §Perf-ANN.
-    masked = jnp.where(state.expanded, INF, state.cand_dists)
-    W = expand_width
-    if W == 1:
-        j = jnp.argmin(masked, axis=1)[:, None]                      # (Q, 1)
-    else:
-        _, j = jax.lax.top_k(-masked, W)                             # (Q, W)
-    best_d = jnp.take_along_axis(masked, j, axis=1)                  # (Q, W)
-    worst = state.cand_dists[:, -1]
-    # termination: nothing expandable, or best unexpanded worse than the
-    # full list's worst (cannot improve the ef set)
-    newly_done = (best_d[:, 0] == INF) | (best_d[:, 0] > worst)
-    done = state.done | newly_done
-    active = ~done
+    with jax.named_scope("beam_select"):
+        masked = jnp.where(state.expanded, INF, state.cand_dists)
+        W = expand_width
+        if W == 1:
+            j = jnp.argmin(masked, axis=1)[:, None]                  # (Q, 1)
+        else:
+            _, j = jax.lax.top_k(-masked, W)                         # (Q, W)
+        best_d = jnp.take_along_axis(masked, j, axis=1)              # (Q, W)
+        worst = state.cand_dists[:, -1]
+        # termination: nothing expandable, or best unexpanded worse than the
+        # full list's worst (cannot improve the ef set)
+        newly_done = (best_d[:, 0] == INF) | (best_d[:, 0] > worst)
+        done = state.done | newly_done
+        active = ~done
 
-    vtx = jnp.take_along_axis(state.cand_ids, j, axis=1)             # (Q, W)
-    expandable = (best_d < INF) & active[:, None]
-    expanded = state.expanded.at[
-        jnp.broadcast_to(jnp.arange(Q)[:, None], j.shape), j
-    ].max(expandable)
+        vtx = jnp.take_along_axis(state.cand_ids, j, axis=1)         # (Q, W)
+        expandable = (best_d < INF) & active[:, None]
+        expanded = state.expanded.at[
+            jnp.broadcast_to(jnp.arange(Q)[:, None], j.shape), j
+        ].max(expandable)
 
     # 2. gather neighbors; mask padding/inactive
-    nbrs = neighbors[jnp.maximum(vtx, 0)].reshape(Q, W * R)          # (Q, W*R)
-    nbrs = jnp.where((nbrs >= 0) & jnp.repeat(expandable, R, axis=1), nbrs,
-                     INVALID)
-    # dedup within the row (two expanded vertices may share a neighbor)
-    if W > 1:
-        nbrs = dedup_rows(nbrs)
+    with jax.named_scope("beam_expand"):
+        nbrs = neighbors[jnp.maximum(vtx, 0)].reshape(Q, W * R)      # (Q, W*R)
+        nbrs = jnp.where((nbrs >= 0) & jnp.repeat(expandable, R, axis=1),
+                         nbrs, INVALID)
+        # dedup within the row (two expanded vertices may share a neighbor)
+        if W > 1:
+            nbrs = dedup_rows(nbrs)
 
     # 3. score + mask + account + mark visited, through the scorer axis
     # (DESIGN.md §8). The visited-bitmap test and the validity mask are fused
     # into the kernel epilogue either way: the kernel returns (+inf, INVALID)
     # for padding/visited entries directly.
-    nd, nbrs = get_scorer(scorer).score(
-        scorer_state, queries, base, nbrs, state.visited,
-        metric=metric, r_tile=r_tile,
-    )                                                                # (Q, W*R)
-    n_comps = state.n_comps + (nbrs >= 0).sum(axis=1, dtype=jnp.int32)
-    visited = _mark_visited(state.visited, nbrs)
+    with jax.named_scope("beam_score"):
+        nd, nbrs = get_scorer(scorer).score(
+            scorer_state, queries, base, nbrs, state.visited,
+            metric=metric, r_tile=r_tile,
+        )                                                            # (Q, W*R)
+        n_comps = state.n_comps + (nbrs >= 0).sum(axis=1, dtype=jnp.int32)
+    with jax.named_scope("beam_visited"):
+        visited = _mark_visited(state.visited, nbrs)
 
     # 4. merge (no dedup needed: visited-filtering guarantees uniqueness).
     # Bounded top-k instead of a full-width argsort: only the ef best of the
     # (ef + W*R) merged candidates survive, so selecting them directly is
     # O(m log ef) work instead of O(m log m) — and lax.top_k breaks ties by
     # lowest index, matching the stable ascending sort it replaces.
-    all_d = jnp.concatenate([state.cand_dists, nd], axis=1)
-    all_i = jnp.concatenate([state.cand_ids, nbrs], axis=1)
-    all_e = jnp.concatenate(
-        [expanded, jnp.zeros((Q, nbrs.shape[1]), bool)], axis=1
-    )
-    cand_d, order = topk_smallest(all_d, ef)
-    cand_i = jnp.take_along_axis(all_i, order, axis=1)
-    cand_e = jnp.take_along_axis(all_e, order, axis=1)
+    with jax.named_scope("beam_merge"):
+        all_d = jnp.concatenate([state.cand_dists, nd], axis=1)
+        all_i = jnp.concatenate([state.cand_ids, nbrs], axis=1)
+        all_e = jnp.concatenate(
+            [expanded, jnp.zeros((Q, nbrs.shape[1]), bool)], axis=1
+        )
+        cand_d, order = topk_smallest(all_d, ef)
+        cand_i = jnp.take_along_axis(all_i, order, axis=1)
+        cand_e = jnp.take_along_axis(all_e, order, axis=1)
 
-    # frozen queries keep their state
-    keep = lambda new, old: jnp.where(done[:, None], old, new)
-    cand_i = keep(cand_i, state.cand_ids)
-    cand_d = keep(cand_d, state.cand_dists)
-    cand_e = keep(cand_e, state.expanded)
-    visited = jnp.where(done[:, None], state.visited, visited)
-    n_comps = jnp.where(done, state.n_comps, n_comps)
+        # frozen queries keep their state
+        keep = lambda new, old: jnp.where(done[:, None], old, new)
+        cand_i = keep(cand_i, state.cand_ids)
+        cand_d = keep(cand_d, state.cand_dists)
+        cand_e = keep(cand_e, state.expanded)
+        n_comps = jnp.where(done, state.n_comps, n_comps)
+    with jax.named_scope("beam_visited"):
+        visited = jnp.where(done[:, None], state.visited, visited)
 
     # per-query stability freeze (term="stable", DESIGN.md §12): a row whose
     # top-k has not strictly improved for stable_steps consecutive steps is
@@ -341,21 +355,22 @@ def _step(state: _State, queries, base, neighbors, metric,
     # rule above.
     stale = state.stale
     restarts_used = state.restarts_used
-    if term == "stable":
-        kk = min(k, ef)
-        improved = (cand_d[:, :kk] < state.cand_dists[:, :kk]).any(axis=1)
-        stale = jnp.where(done, state.stale,
-                          jnp.where(improved, 0, state.stale + 1))
-        done = done | (stale >= stable_steps)
-    if restarts > 0:
-        (cand_i, cand_d, cand_e, visited, n_comps, done, stale,
-         restarts_used) = _restart_rows(
-            queries, base, metric, r_tile, scorer, scorer_state,
-            restart_keys, restarts, restart_gate,
-            neighbors.shape[0], min(ef, 8), state.seed_best,
-            cand_i, cand_d, cand_e, visited, n_comps, done, stale,
-            restarts_used,
-        )
+    with jax.named_scope("beam_freeze"):
+        if term == "stable":
+            kk = min(k, ef)
+            improved = (cand_d[:, :kk] < state.cand_dists[:, :kk]).any(axis=1)
+            stale = jnp.where(done, state.stale,
+                              jnp.where(improved, 0, state.stale + 1))
+            done = done | (stale >= stable_steps)
+        if restarts > 0:
+            (cand_i, cand_d, cand_e, visited, n_comps, done, stale,
+             restarts_used) = _restart_rows(
+                queries, base, metric, r_tile, scorer, scorer_state,
+                restart_keys, restarts, restart_gate,
+                neighbors.shape[0], min(ef, 8), state.seed_best,
+                cand_i, cand_d, cand_e, visited, n_comps, done, stale,
+                restarts_used,
+            )
     return _State(
         cand_ids=cand_i,
         cand_dists=cand_d,
@@ -370,6 +385,7 @@ def _step(state: _State, queries, base, neighbors, metric,
     )
 
 
+@jax.named_scope("beam_finalize")
 def _finalize(state: _State, queries, base, k, metric, r_tile,
               scorer: str, scorer_state, rerank: int) -> SearchResult:
     """Loop epilogue. Exact scorer: slice the candidate list. Compressed

@@ -450,7 +450,8 @@ class GraphBuilder:
 
     Stage names are resolved at construction time so a typo fails before any
     NN-Descent rounds burn; ``build`` runs the three stages, times each, and
-    assembles the :class:`BuildReport`."""
+    assembles the :class:`BuildReport`. The construct and diversify stages
+    are ``ann.construct`` / ``ann.diversify`` spans in a profiler trace."""
 
     def __init__(self, spec: BuildSpec):
         self.spec = spec
@@ -477,10 +478,11 @@ class GraphBuilder:
                 f"(d={base.shape[1]}, pq_m={spec.pq_m})"
             )
 
-        t0 = time.perf_counter()
-        cres = self._construct(base, spec, key, verbose)
-        jax.block_until_ready(cres.graph.neighbors)
-        t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("ann.construct"):
+            t0 = time.perf_counter()
+            cres = self._construct(base, spec, key, verbose)
+            jax.block_until_ready(cres.graph.neighbors)
+            t1 = time.perf_counter()
 
         proxy = -1.0
         if spec.proxy_sample:
@@ -489,15 +491,16 @@ class GraphBuilder:
             proxy = graph_recall_proxy(base, proxy_graph, metric=spec.metric,
                                        sample=spec.proxy_sample)
 
-        t2 = time.perf_counter()
-        if cres.stats.get("inline_diversify"):
-            # the construct diversified per insert (incremental); a second
-            # global pass would double-prune the same edges
-            graph, dstats = cres.graph, {"dropped_reverse_edges": 0}
-        else:
-            graph, dstats = self._diversify(base, cres.graph, spec)
-        jax.block_until_ready(graph.neighbors)
-        t3 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("ann.diversify"):
+            t2 = time.perf_counter()
+            if cres.stats.get("inline_diversify"):
+                # the construct diversified per insert (incremental); a
+                # second global pass would double-prune the same edges
+                graph, dstats = cres.graph, {"dropped_reverse_edges": 0}
+            else:
+                graph, dstats = self._diversify(base, cres.graph, spec)
+            jax.block_until_ready(graph.neighbors)
+            t3 = time.perf_counter()
 
         pq = self._compress(base, spec, key)
         if pq is not None:

@@ -13,6 +13,11 @@ the CPU algorithm becomes fixed-shape rounds:
   5. merge into the sorted K-list with fixed-shape dedup.
 
 The update counter gives the standard early-termination rule (delta * n * K).
+
+A round's phases run under ``jax.named_scope``s that name them in a device
+trace: ``nd_join`` (1-3), ``nd_score`` (4) and ``nd_merge`` (4b-5); the
+random start is ``nd_init``. Each round on the host is an ``ann.nd_round``
+profiler span.
 """
 from __future__ import annotations
 
@@ -72,80 +77,87 @@ def _score_chunked(base, pool, metric, chunk):
 
 def _round(base, ids, dists, isnew, key, cfg: NNDescentConfig, metric: str):
     n, k = ids.shape
-    kf, kr, ks = jax.random.split(key, 3)
 
-    # -- 1. new-biased sampling of own neighbors ---------------------------
-    # Priority = random, boosted for new entries; take top-S positions.
-    prio = jax.random.uniform(kf, (n, k)) + isnew.astype(jnp.float32)
-    sel = jnp.argsort(-prio, axis=-1)[:, : cfg.sample]            # (n, S)
-    nbr = jnp.take_along_axis(ids, sel, axis=-1)                   # (n, S)
+    with jax.named_scope("nd_join"):
+        kf, kr, ks = jax.random.split(key, 3)
+        # -- 1. new-biased sampling of own neighbors --------------------------
+        # Priority = random, boosted for new entries; take top-S positions.
+        prio = jax.random.uniform(kf, (n, k)) + isnew.astype(jnp.float32)
+        sel = jnp.argsort(-prio, axis=-1)[:, : cfg.sample]            # (n, S)
+        nbr = jnp.take_along_axis(ids, sel, axis=-1)                   # (n, S)
 
-    # -- 2. neighbor-of-neighbor expansion ---------------------------------
-    safe_nbr = jnp.maximum(nbr, 0)
-    nn_lists = ids[safe_nbr]                                       # (n, S, k)
-    cols = jax.random.randint(ks, (n, cfg.sample, cfg.sample_nn), 0, k)
-    nn_cand = jnp.take_along_axis(nn_lists, cols, axis=-1)         # (n, S, S2)
-    nn_cand = jnp.where(nbr[..., None] >= 0, nn_cand, INVALID)
-    nn_cand = nn_cand.reshape(n, -1)
+        # -- 2. neighbor-of-neighbor expansion --------------------------------
+        safe_nbr = jnp.maximum(nbr, 0)
+        nn_lists = ids[safe_nbr]                                   # (n, S, k)
+        cols = jax.random.randint(ks, (n, cfg.sample, cfg.sample_nn), 0, k)
+        nn_cand = jnp.take_along_axis(nn_lists, cols, axis=-1)    # (n, S, S2)
+        nn_cand = jnp.where(nbr[..., None] >= 0, nn_cand, INVALID)
+        nn_cand = nn_cand.reshape(n, -1)
 
-    # -- 3. reverse-edge candidates (random-slot scatter) -------------------
-    src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
-    tgt = ids
-    slots = jax.random.randint(kr, (n, k), 0, cfg.reverse)
-    rev = jnp.full((n, cfg.reverse), INVALID, jnp.int32)
-    valid = tgt >= 0
-    rev = rev.at[jnp.where(valid, tgt, 0).ravel(), slots.ravel()].set(
-        jnp.where(valid, src, INVALID).ravel(), mode="drop"
-    )
-    # Also join reverse candidates' neighborhoods (one hop), sampled:
-    rev_sel = rev[:, : max(2, cfg.reverse // 4)]
-    rev_nn = ids[jnp.maximum(rev_sel, 0)][..., : cfg.sample_nn]
-    rev_nn = jnp.where(rev_sel[..., None] >= 0, rev_nn, INVALID).reshape(n, -1)
+        # -- 3. reverse-edge candidates (random-slot scatter) -----------------
+        src = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
+        tgt = ids
+        slots = jax.random.randint(kr, (n, k), 0, cfg.reverse)
+        rev = jnp.full((n, cfg.reverse), INVALID, jnp.int32)
+        valid = tgt >= 0
+        rev = rev.at[jnp.where(valid, tgt, 0).ravel(), slots.ravel()].set(
+            jnp.where(valid, src, INVALID).ravel(), mode="drop"
+        )
+        # Also join reverse candidates' neighborhoods (one hop), sampled:
+        rev_sel = rev[:, : max(2, cfg.reverse // 4)]
+        rev_nn = ids[jnp.maximum(rev_sel, 0)][..., : cfg.sample_nn]
+        rev_nn = jnp.where(rev_sel[..., None] >= 0, rev_nn,
+                           INVALID).reshape(n, -1)
 
-    pool = jnp.concatenate([nn_cand, rev, rev_nn], axis=1)         # (n, C)
-    pool = jnp.where(pool == jnp.arange(n, dtype=jnp.int32)[:, None], INVALID, pool)
+        pool = jnp.concatenate([nn_cand, rev, rev_nn], axis=1)         # (n, C)
+        pool = jnp.where(pool == jnp.arange(n, dtype=jnp.int32)[:, None],
+                         INVALID, pool)
 
     # -- 4. score ------------------------------------------------------------
-    cand_d = _score_chunked(base, pool, metric, cfg.chunk)
+    with jax.named_scope("nd_score"):
+        cand_d = _score_chunked(base, pool, metric, cfg.chunk)
 
-    # -- 4b. symmetric push-back: the original local join updates BOTH ends of
-    # a compared pair. Scatter each vertex's k best scored edges (v -> c, d)
-    # into c's incoming buffer (random slot, collisions drop) and merge them
-    # too. Pushing the k best rather than all C candidates keeps the edges
-    # that can enter c's list and cuts the scatter (which XLA:TPU lowers to
-    # a sort of its indices) from n*C to n*k updates. One scatter of the
-    # edge's flat position carries both ends: (id, dist) pairs stay
-    # consistent whichever writer wins a slot.
-    _, best = jax.lax.top_k(-cand_d, k)
-    push_pool = jnp.take_along_axis(pool, best, axis=1)            # (n, k)
-    push_cand = jnp.take_along_axis(cand_d, best, axis=1)
-    C = push_pool.shape[1]
-    kp = jax.random.fold_in(key, 7)
-    rb = max(k, cfg.reverse)
-    pslots = jax.random.randint(kp, (n, C), 0, rb)
-    pvalid = (push_pool >= 0) & jnp.isfinite(push_cand)
-    flat_tgt = jnp.where(pvalid, push_pool, 0).ravel()
-    pos = jnp.arange(n * C, dtype=jnp.int32)
-    push_pos = jnp.full((n, rb), INVALID, jnp.int32).at[
-        flat_tgt, pslots.ravel()
-    ].set(jnp.where(pvalid.ravel(), pos, INVALID), mode="drop")
-    pushed = push_pos >= 0
-    push_i = jnp.where(pushed, push_pos // C, INVALID)
-    push_d = jnp.where(pushed, push_cand.ravel()[jnp.maximum(push_pos, 0)],
-                       jnp.inf)
+    with jax.named_scope("nd_merge"):
+        # -- 4b. symmetric push-back: the original local join updates BOTH
+        # ends of a compared pair. Scatter each vertex's k best scored edges
+        # (v -> c, d) into c's incoming buffer (random slot, collisions drop)
+        # and merge them too. Pushing the k best rather than all C candidates
+        # keeps the edges that can enter c's list and cuts the scatter (which
+        # XLA:TPU lowers to a sort of its indices) from n*C to n*k updates.
+        # One scatter of the edge's flat position carries both ends: (id,
+        # dist) pairs stay consistent whichever writer wins a slot.
+        _, best = jax.lax.top_k(-cand_d, k)
+        push_pool = jnp.take_along_axis(pool, best, axis=1)            # (n, k)
+        push_cand = jnp.take_along_axis(cand_d, best, axis=1)
+        C = push_pool.shape[1]
+        kp = jax.random.fold_in(key, 7)
+        rb = max(k, cfg.reverse)
+        pslots = jax.random.randint(kp, (n, C), 0, rb)
+        pvalid = (push_pool >= 0) & jnp.isfinite(push_cand)
+        flat_tgt = jnp.where(pvalid, push_pool, 0).ravel()
+        pos = jnp.arange(n * C, dtype=jnp.int32)
+        push_pos = jnp.full((n, rb), INVALID, jnp.int32).at[
+            flat_tgt, pslots.ravel()
+        ].set(jnp.where(pvalid.ravel(), pos, INVALID), mode="drop")
+        pushed = push_pos >= 0
+        push_i = jnp.where(pushed, push_pos // C, INVALID)
+        push_d = jnp.where(pushed, push_cand.ravel()[jnp.maximum(push_pos, 0)],
+                           jnp.inf)
 
-    # -- 5. merge ------------------------------------------------------------
-    def merge(row_d, row_i, cd, ci, pd, pi):
-        d, i = dedup_by_id(
-            jnp.concatenate([row_d, cd, pd]), jnp.concatenate([row_i, ci, pi])
-        )
-        return d[:k], i[:k]
+        # -- 5. merge ---------------------------------------------------------
+        def merge(row_d, row_i, cd, ci, pd, pi):
+            d, i = dedup_by_id(
+                jnp.concatenate([row_d, cd, pd]),
+                jnp.concatenate([row_i, ci, pi]),
+            )
+            return d[:k], i[:k]
 
-    new_d, new_i = jax.vmap(merge)(dists, ids, cand_d, pool, push_d, push_i)
-    # an entry is "new" if its id was not in the previous list
-    was_in = (new_i[:, :, None] == ids[:, None, :]).any(-1)
-    new_flag = (~was_in) & (new_i != INVALID)
-    n_updates = new_flag.sum()
+        new_d, new_i = jax.vmap(merge)(dists, ids, cand_d, pool, push_d,
+                                       push_i)
+        # an entry is "new" if its id was not in the previous list
+        was_in = (new_i[:, :, None] == ids[:, None, :]).any(-1)
+        new_flag = (~was_in) & (new_i != INVALID)
+        n_updates = new_flag.sum()
     return new_i, new_d, new_flag, n_updates
 
 
@@ -155,6 +167,7 @@ def _round_jit(base, ids, dists, isnew, key, cfg, metric):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "metric"))
+@jax.named_scope("nd_init")
 def _init_jit(base, key, cfg, metric):
     """Random k-lists, scored and deduped: the state round 0 starts from."""
     ids = _random_init(key, base.shape[0], cfg.k)
@@ -203,8 +216,10 @@ def build_knn_graph_with_stats(
     for r in range(cfg.rounds):
         t0 = time.perf_counter()
         key, kr = jax.random.split(key)
-        ids, dists, isnew, n_up = _round_jit(base, ids, dists, isnew, kr, cfg, metric)
-        n_up = int(n_up)
+        with jax.profiler.TraceAnnotation("ann.nd_round", round=r):
+            ids, dists, isnew, n_up = _round_jit(base, ids, dists, isnew, kr,
+                                                 cfg, metric)
+            n_up = int(n_up)
         curve.append(n_up)
         if verbose:
             print(f"[nndescent] round {r}: {n_up} updates, "

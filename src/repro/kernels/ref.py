@@ -68,9 +68,12 @@ def gather_distance_onehot_ref(
     return _distances_from_rows(queries, ids, rows, metric)
 
 
+@jax.named_scope("visited_test")
 def visited_mask_ref(ids: jax.Array, visited: jax.Array) -> jax.Array:
     """ids (Q, R) against a bit-packed (Q, ceil(n/32)) uint32 visited bitmap
-    -> ids with padding (< 0) and already-visited entries set to -1."""
+    -> ids with padding (< 0) and already-visited entries set to -1. Every
+    masked scorer runs this test under the ``visited_test`` scope, inside
+    whatever scope its caller runs in."""
     Q, W = visited.shape
     safe = jnp.maximum(ids, 0)
     q = jnp.broadcast_to(jnp.arange(Q)[:, None], ids.shape)

@@ -38,9 +38,17 @@ module is the serving layer between the two:
   ``poll()`` retires finished batches without blocking (``is_ready``), so
   completion timestamps track device completion, not caller convenience.
 * **Accounting.** Every request carries enqueue/admit/dispatch/complete
-  timestamps; ``stats()`` rolls them into p50/p90/p99 latency, queue wait,
-  bucket occupancy and shed counts — the columns ``benchmarks/loadgen.py``
-  sweeps against offered QPS into ``BENCH_engine.json``.
+  timestamps and its beam step count; ``stats()`` rolls them into
+  p50/p90/p99 latency, queue wait, bucket occupancy and shed counts — the
+  columns ``benchmarks/loadgen.py`` sweeps against offered QPS into
+  ``BENCH_engine.json``.
+* **Spans.** Under ``jax.profiler`` the host work is a tree of
+  ``TraceAnnotation`` spans on the device trace's clock, each tagged with
+  the request's ``rid`` and ``bucket``: ``ann.submit`` and ``ann.poll``
+  hold ``ann.admit`` (children ``ann.seed``: transfer and seeding,
+  ``ann.pad``, ``ann.dispatch``: the beam core call) and ``ann.retire``
+  (children ``ann.wait``: the device wait, ``ann.fetch``: the copies to the
+  host). Untraced, a span costs about a microsecond.
 * **Hot swap.** ``swap()`` (DESIGN.md §13) atomically flips serving to a
   new index version: the incoming index is prepared and fully warmed OFF
   the serving path, then the flip is two attribute assignments — zero
@@ -99,7 +107,7 @@ class Request:
     ids: np.ndarray | None = None       # (q, k) answers, real rows only
     dists: np.ndarray | None = None     # (q, k)
     n_comps: np.ndarray | None = None   # (q,)
-    bytes_touched: np.ndarray | None = None  # (q,) scored + rerank bytes (§15)
+    n_steps: jax.Array | None = None    # () beam steps, left on the device
 
     @property
     def latency_s(self) -> float:
@@ -122,6 +130,11 @@ def _percentiles(ms: np.ndarray) -> dict:
         "p99_ms": round(float(np.percentile(ms, 99)), 3),
         "mean_ms": round(float(ms.mean()), 3),
     }
+
+
+def _span(name: str, **tags):
+    """A host span of the serving path in a ``jax.profiler`` trace."""
+    return jax.profiler.TraceAnnotation("ann." + name, **tags)
 
 
 @functools.partial(jax.jit, static_argnames=("bucket",))
@@ -257,7 +270,8 @@ class AnnServer:
     def _search_padded(self, rows: np.ndarray, key: jax.Array,
                        bucket: int, *, searcher: Searcher | None = None,
                        spec: SearchSpec | None = None,
-                       filter: FilterSpec | None = None) -> SearchResult:
+                       filter: FilterSpec | None = None,
+                       rid: int = -1) -> SearchResult:
         """Transfer + seed + pad + dispatch, all asynchronous. Seeding uses
         the request's REAL row count (PRNG parity with a direct search);
         padding to the bucket happens after, with entries INVALID, comps 0
@@ -265,20 +279,25 @@ class AnnServer:
         ``spec`` target an index other than the serving one (warming an
         incoming index pre-flip). ``filter`` overrides ``spec.filter`` for
         this request (§14): denied-seed redraws key off the ROW INDEX, so
-        the padded rows redraw exactly as a direct filtered search would."""
+        the padded rows redraw exactly as a direct filtered search would.
+        ``rid`` tags the spans (-1 off the request path)."""
         searcher = self.searcher if searcher is None else searcher
         spec = self.spec if spec is None else spec
         if filter is not None:
             spec = spec._replace(filter=filter)
-        dev = jax.device_put(rows)  # async: overlaps the in-flight batch
-        ent, ecomps = searcher.seed(dev, spec, key)
-        dev, ent, ecomps, valid = _pad_to_bucket(dev, ent, ecomps, bucket)
+        with _span("seed", rid=rid, bucket=bucket):
+            dev = jax.device_put(rows)  # async: overlaps the in-flight batch
+            ent, ecomps = searcher.seed(dev, spec, key)
+        with _span("pad", rid=rid, bucket=bucket):
+            dev, ent, ecomps, valid = _pad_to_bucket(dev, ent, ecomps,
+                                                     bucket)
         # the request key ALSO rides into the search: restart keys are
         # fold_in(key, row_index), so the real rows of a padded bucket draw
         # the exact restart seeds a direct search would (pad rows hold keys
         # too but can never restart — they finish with an empty beam)
-        return searcher.search(dev, spec, key, entries=ent,
-                               entry_comps=ecomps, q_valid=valid)
+        with _span("dispatch", rid=rid, bucket=bucket):
+            return searcher.search(dev, spec, key, entries=ent,
+                                   entry_comps=ecomps, q_valid=valid)
 
     # -- hot swap (DESIGN.md §13) ---------------------------------------------
 
@@ -335,18 +354,19 @@ class AnnServer:
             raise ValueError(f"rows must be (q, d), got shape {rows.shape}")
         rid = self._rid
         self._rid += 1
-        if key is None:
-            key = jax.random.fold_in(self.searcher.key, 1_000_003 + rid)
-        req = Request(rid=rid, queries=rows, key=key, t_enqueue=now,
-                      filter=filter)
-        req.bucket = self.pick_bucket(rows.shape[0])  # reject-too-big first
-        if len(self.queue) >= self.config.max_queue_depth:
-            req.shed = True
-            self.shed.append(req)
-            return req
-        self.queue.append(req)
-        if advance:
-            self.poll(now)
+        with _span("submit", rid=rid):
+            if key is None:
+                key = jax.random.fold_in(self.searcher.key, 1_000_003 + rid)
+            req = Request(rid=rid, queries=rows, key=key, t_enqueue=now,
+                          filter=filter)
+            req.bucket = self.pick_bucket(rows.shape[0])  # reject-too-big
+            if len(self.queue) >= self.config.max_queue_depth:
+                req.shed = True
+                self.shed.append(req)
+                return req
+            self.queue.append(req)
+            if advance:
+                self.poll(now)
         return req
 
     def submit_wait(self, rows, key: jax.Array | None = None,
@@ -365,10 +385,12 @@ class AnnServer:
         from the head of the live window (dispatch order == completion
         order on one device stream), then admit queued requests up to the
         admission cap."""
-        while self.live and self._ready(self.live[0]):
-            self._retire(self.live.popleft())
-        while self.queue and len(self.live) < self.config.max_live_batches:
-            self._admit(self.queue.popleft())
+        with _span("poll"):
+            while self.live and self._ready(self.live[0]):
+                self._retire(self.live.popleft())
+            cap = self.config.max_live_batches
+            while self.queue and len(self.live) < cap:
+                self._admit(self.queue.popleft())
 
     def drain(self) -> list[Request]:
         """Block until every queued and in-flight request completes."""
@@ -383,10 +405,11 @@ class AnnServer:
         return True if is_ready is None else bool(is_ready())
 
     def _admit(self, req: Request) -> None:
-        req.t_admit = self.clock()
-        res = self._search_padded(req.queries, req.key, req.bucket,
-                                  filter=req.filter)
-        req.t_dispatch = self.clock()
+        with _span("admit", rid=req.rid, bucket=req.bucket):
+            req.t_admit = self.clock()
+            res = self._search_padded(req.queries, req.key, req.bucket,
+                                      filter=req.filter, rid=req.rid)
+            req.t_dispatch = self.clock()
         qn = req.queries.shape[0]
         self.bucket_counts[req.bucket] += 1
         self.real_rows += qn
@@ -395,15 +418,18 @@ class AnnServer:
 
     def _retire(self, lb: _LiveBatch) -> None:
         res, req = lb.result, lb.request
-        jax.block_until_ready(res.ids)
-        req.t_complete = self.clock()
-        qn = req.queries.shape[0]
-        req.ids = np.asarray(res.ids)[:qn]
-        req.dists = np.asarray(res.dists)[:qn]
-        req.n_comps = np.asarray(res.n_comps)[:qn]
-        bt = np.asarray(res.bytes_touched)
-        req.bytes_touched = bt[:qn] if bt.ndim else None
-        self.completed.append(req)
+        tags = dict(rid=req.rid, bucket=req.bucket)
+        with _span("retire", **tags):
+            with _span("wait", **tags):
+                jax.block_until_ready(res.ids)
+            req.t_complete = self.clock()
+            qn = req.queries.shape[0]
+            with _span("fetch", **tags):
+                req.ids = np.asarray(res.ids)[:qn]
+                req.dists = np.asarray(res.dists)[:qn]
+                req.n_comps = np.asarray(res.n_comps)[:qn]
+            req.n_steps = res.n_steps
+            self.completed.append(req)
 
     # -- rollups --------------------------------------------------------------
 
