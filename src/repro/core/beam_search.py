@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .scorers import get_scorer
-from .topk import INVALID, topk_smallest
+from .topk import INVALID, topk_smallest, topk_smallest_with
 
 INF = jnp.float32(jnp.inf)
 
@@ -210,9 +210,7 @@ def _init_state(queries, base, neighbors, entry_ids, ef, metric,
     cand_i = jnp.concatenate(
         [entry_ids, jnp.full((Q, pad), INVALID, jnp.int32)], axis=1
     )
-    order = jnp.argsort(cand_d, axis=1, stable=True)
-    cand_d = jnp.take_along_axis(cand_d, order, axis=1)
-    cand_i = jnp.take_along_axis(cand_i, order, axis=1)
+    cand_d, cand_i = topk_smallest_with(cand_d, (cand_i,), ef)
     return _State(
         cand_ids=cand_i,
         cand_dists=cand_d,
@@ -260,9 +258,7 @@ def _restart_rows(queries, base, metric, r_tile, scorer, scorer_state,
     all_d = jnp.concatenate([cand_d, rd], axis=1)
     all_i = jnp.concatenate([cand_i, rids], axis=1)
     all_e = jnp.concatenate([cand_e, jnp.zeros((Q, E), bool)], axis=1)
-    cand_d, order = topk_smallest(all_d, ef)
-    cand_i = jnp.take_along_axis(all_i, order, axis=1)
-    cand_e = jnp.take_along_axis(all_e, order, axis=1)
+    cand_d, cand_i, cand_e = topk_smallest_with(all_d, (all_i, all_e), ef)
     return (cand_i, cand_d, cand_e, visited, n_comps,
             done & ~can, jnp.where(can, 0, stale),
             restarts_used + can.astype(jnp.int32))
@@ -324,19 +320,17 @@ def _step(state: _State, queries, base, neighbors, metric,
         visited = _mark_visited(state.visited, nbrs)
 
     # 4. merge (no dedup needed: visited-filtering guarantees uniqueness).
-    # Bounded top-k instead of a full-width argsort: only the ef best of the
-    # (ef + W*R) merged candidates survive, so selecting them directly is
-    # O(m log ef) work instead of O(m log m) — and lax.top_k breaks ties by
-    # lowest index, matching the stable ascending sort it replaces.
+    # The ef best of the (ef + W*R) merged candidates, ids and expanded flags
+    # carried through one stable sort (DESIGN.md §2 rule 4): on the TPU top_k
+    # is that same full-width sort, while reordering ids and flags by its
+    # index would take a gather each. Ties keep the lowest index first.
     with jax.named_scope("beam_merge"):
         all_d = jnp.concatenate([state.cand_dists, nd], axis=1)
         all_i = jnp.concatenate([state.cand_ids, nbrs], axis=1)
         all_e = jnp.concatenate(
             [expanded, jnp.zeros((Q, nbrs.shape[1]), bool)], axis=1
         )
-        cand_d, order = topk_smallest(all_d, ef)
-        cand_i = jnp.take_along_axis(all_i, order, axis=1)
-        cand_e = jnp.take_along_axis(all_e, order, axis=1)
+        cand_d, cand_i, cand_e = topk_smallest_with(all_d, (all_i, all_e), ef)
 
         # frozen queries keep their state
         keep = lambda new, old: jnp.where(done[:, None], old, new)

@@ -18,6 +18,24 @@ def topk_smallest(dists: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     return -neg_vals, idx
 
 
+def topk_smallest_with(
+    dists: jax.Array, payloads: tuple[jax.Array, ...], k: int
+) -> tuple[jax.Array, ...]:
+    """(.., m) -> (values, *payloads) of the k smallest, ascending.
+
+    Each payload has the shape of ``dists`` and travels with its distance
+    through one stable sort keyed on ``dists`` alone, so ties keep the lowest
+    index first, as ``lax.top_k`` does. On the TPU ``top_k`` is itself a
+    full-width stable sort with an index payload; carrying the payloads
+    through the sort replaces the gathers that would reorder them by index.
+    Distances that compare equal tie, -0.0 and +0.0 among them, where
+    ``top_k`` puts -0.0 first; of the metrics only ``ip`` yields a -0.0.
+    """
+    out = jax.lax.sort((dists, *payloads), dimension=dists.ndim - 1,
+                       num_keys=1, is_stable=True)
+    return tuple(x[..., :k] for x in out)
+
+
 def sort_by_distance(dists: jax.Array, ids: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Sort (…, m) candidate lists ascending by distance (stable)."""
     order = jnp.argsort(dists, axis=-1, stable=True)

@@ -34,6 +34,43 @@ def test_topk_merge_dedup():
     assert float(d[0]) == pytest.approx(0.05)
 
 
+@pytest.mark.parametrize("m,k", [(148, 128), (532, 512), (128 + 8, 128)])
+def test_topk_smallest_with_matches_topk_and_gathers(m, k):
+    """The beam merge's carried sort against the formulation it replaced
+    (``topk_smallest``, then one ``take_along_axis`` per payload), at the
+    merge widths of ef 128 and 512 with R = 20 and of a restart (ef + 8):
+    rows of tied distances, +inf padding and INVALID ids."""
+    rng = np.random.default_rng(m)
+    Q = 16
+    d = (rng.integers(0, 8, (Q, m)) / 4).astype(np.float32)  # many ties
+    ids = rng.integers(0, 1_000_000, (Q, m)).astype(np.int32)
+    pad = rng.random((Q, m)) < 0.3
+    pad[0] = True                                           # an empty row
+    d[pad] = np.inf
+    ids[pad] = -1
+    ids[1, ::3] = -1                     # INVALID beside finite distances
+    flags = rng.random((Q, m)) < 0.5
+
+    want_d, order = topk.topk_smallest(jnp.asarray(d), k)
+    want_i = jnp.take_along_axis(jnp.asarray(ids), order, axis=1)
+    want_e = jnp.take_along_axis(jnp.asarray(flags), order, axis=1)
+    got_d, got_i, got_e = jax.jit(topk.topk_smallest_with, static_argnums=2)(
+        jnp.asarray(d), (jnp.asarray(ids), jnp.asarray(flags)), k)
+    assert got_d.shape == got_i.shape == got_e.shape == (Q, k)
+    np.testing.assert_array_equal(np.asarray(got_d), np.asarray(want_d))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_e), np.asarray(want_e))
+
+
+def test_topk_smallest_with_signed_zeros_tie_by_index():
+    """-0.0 and +0.0 compare equal, so they keep their order like any tie."""
+    d = jnp.array([[0.0, -0.0, 1.0, -0.0, -1e-7]], jnp.float32)
+    ids = jnp.arange(5, dtype=jnp.int32)[None]
+    got_d, got_i = topk.topk_smallest_with(d, (ids,), 4)
+    assert np.asarray(got_i).tolist() == [[4, 0, 1, 3]]
+    assert np.signbit(np.asarray(got_d)).tolist() == [[True, False, True, True]]
+
+
 def test_exact_search_vs_numpy():
     k = jax.random.PRNGKey(2)
     base = jax.random.normal(k, (500, 12))

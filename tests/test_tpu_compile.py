@@ -12,6 +12,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,3 +132,29 @@ def test_beam_step_compiles(compile_for, monkeypatch, scorer):
     run(lambda st, q, b, nb, sc: bs._step(st, q, b, nb, "l2", scorer=scorer,
                                           scorer_state=sc, k=10),
         state, *args[:3], state_ops)
+
+
+@pytest.mark.parametrize("n,d,ef,metric", [
+    (1_000_000, 128, 128, "l2"),     # sift1m.batch
+    (1_200_000, 100, 512, "cos"),    # glove1m.batch
+])
+def test_beam_merge_compiles_without_gathers(compile_for, monkeypatch, n, d,
+                                             ef, metric):
+    """The merge of a batch cell's beam step (Q 256, R 20) carries ids and
+    expanded flags through its sort: no gather in the compiled step carries
+    the ``beam_merge`` scope."""
+    from repro.core import beam_search as bs
+
+    monkeypatch.setenv("REPRO_PALLAS", "native")
+    S, run = compile_for
+    Q = 256
+    args = (S((Q, d)), S((n, d)), S((n, 20), jnp.int32),
+            S((Q, 8), jnp.int32))
+    state = jax.eval_shape(
+        lambda q, b, nb, e: bs._init_state(q, b, nb, e, ef, metric), *args)
+    state = jax.tree.map(lambda x: S(x.shape, x.dtype), state)
+    hlo = run(lambda st, q, b, nb: bs._step(st, q, b, nb, metric, k=10),
+              state, *args[:3]).as_text()
+    merge = [line for line in hlo.splitlines() if "/beam_merge/" in line]
+    assert any(re.search(r"\ssort\(", line) for line in merge)
+    assert not [line for line in merge if re.search(r"\sgather\(", line)]
